@@ -15,16 +15,18 @@ an artifact):
     last committed FlushRecords: byte-identical output, every acknowledged
     flush decodes bit-exact, ZERO acknowledged frames lost.
 
-The device count is fixed at jax init, so every measured point runs in a
-subprocess with its own XLA_FLAGS=--xla_force_host_platform_device_count=N
-(this module re-enters itself with --worker). Correctness claims raise
-(failing the smoke gate); scaling claims are recorded as claims.
+Every point runs in this one process, on a mesh over the first N of the
+devices it sees: a chip belongs to one process, so no point may run in a
+child. The identity and chaos drills need four devices; on a CPU host set
+XLA_FLAGS=--xla_force_host_platform_device_count=8 before launch. Points
+wider than the visible device count are skipped and reported. Correctness
+claims raise (failing the smoke gate); scaling claims are recorded as
+claims.
 """
 from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 
 OUT_JSON = os.path.join(os.path.dirname(__file__), "BENCH_fleet.json")
@@ -151,44 +153,25 @@ def _worker_chaos(devices: int) -> dict:
     }
 
 
-_WORKERS = {"scale": _worker_scale, "identity": _worker_identity, "chaos": _worker_chaos}
-
-
-def _spawn(mode: str, devices: int, sessions: int = 0) -> dict:
-    """Re-enter this module in a subprocess with N simulated host devices
-    (the count is fixed at jax init, so it cannot change in-process)."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={devices}"
-    ).strip()
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in [os.path.join(root, "src"), root, env.get("PYTHONPATH", "")] if p
-    )
-    cmd = [sys.executable, "-m", "benchmarks.bench_fleet", "--worker", mode,
-           "--devices", str(devices), "--sessions", str(sessions)]
-    proc = subprocess.run(
-        cmd, env=env, cwd=root, capture_output=True, text=True, timeout=1800
-    )
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"fleet worker {mode}@{devices}dev failed:\n{proc.stdout}\n{proc.stderr}"
-        )
-    for line in proc.stdout.splitlines():
-        if line.startswith("FLEET_JSON:"):
-            return json.loads(line[len("FLEET_JSON:"):])
-    raise RuntimeError(f"fleet worker {mode}@{devices}dev printed no result")
-
-
 # ------------------------------------------------------------------- driver --
 def run(quick: bool = True) -> dict:
     from benchmarks.common import fmt_table
 
+    import jax
+
     sessions = 10240  # the 10k-concurrent-sessions operating point
     dev_points = [1, 4, 8] if quick else [1, 2, 4, 8]
-
-    scale = [_spawn("scale", d, sessions) for d in dev_points]
+    visible = jax.device_count()
+    if visible < 4:
+        raise RuntimeError(
+            f"bench_fleet needs >= 4 visible devices, {visible} "
+            f"{jax.devices()[0].platform} device(s) visible; on a CPU host "
+            "launch with XLA_FLAGS=--xla_force_host_platform_device_count=8"
+        )
+    skipped = [d for d in dev_points if d > visible]
+    if skipped:
+        print(f"   skipped device points {skipped}: {visible} devices visible")
+    scale = [_worker_scale(d, sessions) for d in dev_points if d <= visible]
     print(fmt_table(
         scale,
         ["devices", "sessions", "input_mb", "admit_s", "wall_s",
@@ -201,8 +184,8 @@ def run(quick: bool = True) -> dict:
     print("   modeled fleet speedup vs 1 device:",
           {d: round(s, 2) for d, s in speedups.items()})
 
-    identity = _spawn("identity", 4)
-    chaos = _spawn("chaos", 4)
+    identity = _worker_identity(4)
+    chaos = _worker_chaos(4)
     print(fmt_table([identity], list(identity), "identity: 4-way sharded vs gang"))
     print(fmt_table(
         [{k: v for k, v in chaos.items() if k != "fault_events"}],
@@ -260,19 +243,9 @@ def main(argv=None) -> int:
     import argparse
 
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--worker", choices=sorted(_WORKERS),
-                    help="internal: run one measured point in-process")
-    ap.add_argument("--devices", type=int, default=1)
-    ap.add_argument("--sessions", type=int, default=0)
     ap.add_argument("--smoke", action="store_true", help="fast CI subset")
     ap.add_argument("--full", action="store_true", help="all device points")
     args = ap.parse_args(argv)
-
-    if args.worker:
-        fn = _WORKERS[args.worker]
-        kwargs = {"sessions": args.sessions} if args.worker == "scale" else {}
-        print("FLEET_JSON:" + json.dumps(fn(args.devices, **kwargs)))
-        return 0
     run(quick=args.smoke or not args.full)
     return 0
 

@@ -44,8 +44,8 @@ MODULES = [
 #: (bench_egress's correctness claims RAISE on failure, gating the smoke run:
 #: bit-identical frames, D2H-bytes bound, dispatch count unchanged; ALL of
 #: bench_rans's claims raise: ratio uplift, bounded cost, exact roundtrip).
-#: bench_fleet is NOT here: it re-enters itself in subprocesses with
-#: simulated device counts, so CI runs it in its own `fleet` job.
+#: bench_fleet is NOT here: it needs >= 4 visible devices, so CI runs it in
+#: its own `fleet` job over 8 simulated host devices.
 #: bench_chaos is NOT here either: CI runs it in its own `chaos` job
 #: alongside the fault-injection test grid.
 SMOKE_MODULES = [
@@ -66,6 +66,9 @@ def main():
     ap.add_argument("--only", default=None)
     ap.add_argument("--out", default=os.path.join(os.path.dirname(__file__), "results.json"))
     args = ap.parse_args()
+    from repro import compile_cache
+
+    compile_cache.enable()
 
     mods = [args.only] if args.only else (SMOKE_MODULES if args.smoke else MODULES)
     results, failures = {}, []

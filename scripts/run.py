@@ -348,6 +348,9 @@ def main(argv=None) -> int:
     ap.add_argument("--dataset", default="micro", help="dataset name (default: micro)")
     ap.add_argument("-n", type=int, default=1 << 16, help="tuples to stream")
     args = ap.parse_args(argv)
+    from repro import compile_cache
+
+    compile_cache.enable()
 
     if args.list_codecs:
         return list_codecs()

@@ -644,8 +644,7 @@ def negotiate(spec: JobSpec, registry: Optional[DictRegistry] = None) -> Plan:
         if spec.devices > avail:
             raise _err(
                 f"JobSpec.devices={spec.devices} exceeds the {avail} visible "
-                "device(s); launch with XLA_FLAGS=--xla_force_host_platform_"
-                f"device_count={spec.devices} (or shrink devices)"
+                f"{jax.devices()[0].platform} device(s); set devices <= {avail}"
             )
 
     dict_cap: Optional[DictCapability] = None

@@ -29,7 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import api
-from repro import compat
 from repro.api import (  # noqa: F401  (canonical homes are repro.api / repro.cstream)
     CompressResult,
     GangCompressResult,
@@ -207,7 +206,7 @@ def sharded_compress_fn(
         return state, words, total_bits
 
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             shard_step,
             mesh=mesh,
             in_specs=(P(axis), P(axis, None)),

@@ -273,9 +273,11 @@ def encode_section(raw_words: np.ndarray) -> np.ndarray:
     )
     total = int(total)
     # padding chunks past `nchunks` are fully masked: zero counts, states
-    # still RANS_L — they carry no stream words and are dropped here
-    states_np = np.asarray(states[:nchunks], np.uint32).reshape(-1)
-    counts_np = np.asarray(counts[:nchunks], np.uint32).reshape(-1)
+    # still RANS_L — they carry no stream words and are dropped here. The
+    # arrays are fetched whole and cut on the host: a device slice of
+    # data-dependent length would compile one program per length
+    states_np = np.asarray(states, np.uint32)[:nchunks].reshape(-1)
+    counts_np = np.asarray(counts, np.uint32)[:nchunks].reshape(-1)
     table = _pack_u16(np.asarray(freqs, np.uint32))
     enc = np.concatenate(
         [
@@ -283,7 +285,7 @@ def encode_section(raw_words: np.ndarray) -> np.ndarray:
             table,
             states_np,
             _pack_u16(counts_np),
-            _pack_u16(np.asarray(stream[:total], np.uint32)),
+            _pack_u16(np.asarray(stream, np.uint32)[:total]),
         ]
     )
     return enc if enc.size < raw.size else raw
@@ -352,7 +354,7 @@ def decode_section(section: np.ndarray, raw_word_count: int) -> Tuple[np.ndarray
         jnp.int32(n),
         cp,
     )
-    data = np.asarray(syms[:n], np.uint32).astype(np.uint8)
+    data = np.asarray(syms, np.uint32)[:n].astype(np.uint8)
     return _bytes_to_words(data), p
 
 

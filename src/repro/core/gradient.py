@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.algorithms.nuq import mulaw_decode_unsigned, mulaw_encode_unsigned
 
 
@@ -138,7 +137,7 @@ def compressed_grad_sync(
             lambda x: compressed_allreduce_mean(x, axis, cfg), g
         )
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         sync,
         mesh=mesh,
         in_specs=(specs,),

@@ -20,7 +20,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core.algorithms.nuq import mulaw_decode_unsigned, mulaw_encode_unsigned
 
 SCALE_GROUP = 128  # tokens per quantization scale group
@@ -358,7 +357,7 @@ def decode_attend_dlse(
         for a in (e if isinstance(e, tuple) else (e,))
     )
     tok_spec = P(dax, None, None, None)
-    out, new_cl = compat.shard_map(
+    out, new_cl = jax.shard_map(
         local,
         in_specs=(tok_spec, cache_specs, tok_spec, tok_spec),
         out_specs=(tok_spec, cache_specs),

@@ -247,7 +247,7 @@ class _EgressSink:
     already in flight, so the device computes ahead of the host copies —
     the async egress overlap that replaces the old per-execution
     worst-case-buffer copy pass (DESIGN.md §13). Small arrays additionally
-    start `copy_to_host_async` at enqueue time where the backend offers it.
+    start `copy_to_host_async` at enqueue time.
 
     Stream-order contract: 7-bit-packed metadata units (full blocks) must
     all arrive before raw-bitlen units (tail/flush), and every packed unit
@@ -305,8 +305,7 @@ class _EgressSink:
     @staticmethod
     def _start_host_copy(arrs) -> None:
         for a in arrs:
-            if hasattr(a, "copy_to_host_async"):
-                a.copy_to_host_async()
+            a.copy_to_host_async()
 
     def put_chunk(self, tb, payload, total, meta, packed: bool, syms: int, valid: int):
         """One fused-scan chunk: tb int32[C], payload uint32[C*OW] (compacted,
@@ -557,6 +556,15 @@ class CompressionPipeline(BlockedExecutor):
         self.d2h_payload_bytes = 0
         self.d2h_meta_bytes = 0
         self.d2h_ctrl_bytes = 0
+        self._decompressor: Optional["DecompressionPipeline"] = None
+
+    def decompressor(self) -> "DecompressionPipeline":
+        """Decode executor for this pipeline's frames, built once: every
+        session sharing this pipeline (one gang signature) shares its
+        compiled decode programs too."""
+        if self._decompressor is None:
+            self._decompressor = DecompressionPipeline(self.config, codec=self.codec)
+        return self._decompressor
 
     @property
     def d2h_bytes(self) -> int:
@@ -749,7 +757,7 @@ class CompressionPipeline(BlockedExecutor):
         transfer).
 
         `mesh` (a pure `("data",)` fleet mesh, DESIGN.md §14) additionally
-        shards the session axis over the mesh devices via `compat.shard_map`:
+        shards the session axis over the mesh devices via `jax.shard_map`:
         the vmapped body runs per shard over its local session slice, so one
         dispatch covers devices x gang sessions. The body is closed over —
         per-session state (including the shared-dictionary LWW merge, which
@@ -764,10 +772,8 @@ class CompressionPipeline(BlockedExecutor):
             if mesh is not None:
                 from jax.sharding import PartitionSpec
 
-                from repro import compat
-
                 spec = PartitionSpec("data")
-                fn = compat.shard_map(
+                fn = jax.shard_map(
                     fn,
                     mesh=mesh,
                     in_specs=spec,

@@ -2,14 +2,18 @@
 
 TPU adaptation of the paper's cache-aware micro-batching (Fig 11): each grid
 step owns one block of symbols whose working set (codes + bitlens + the
-accumulated bitstream) lives entirely in VMEM — the VMEM-resident analogue of
+accumulated bitstream) lives entirely in on-chip memory — the analogue of
 the paper's L1D-resident micro-batch. Blocks start word-aligned (standard in
 parallel compressors), so grid steps are independent and the grid maps onto
 all cores/chips with zero cross-block carries.
 
-Within a block the symbols are folded sequentially (`lax.fori_loop`) into a
-loop-carried word buffer using the 3-word shift decomposition of a <=64-bit
-code; across blocks the packer is embarrassingly parallel.
+Within a block the symbols are folded sequentially (`lax.fori_loop`) into
+the block's word buffer using the 3-word shift decomposition of a <=64-bit
+code. The fold reads and writes single words at data-dependent offsets,
+which Mosaic lowers only for scalar memory, so the block lives in SMEM and
+the scalar core runs the fold; each block is passed as a (1, width) row of
+a (nblocks, 1, width) array, whose last two dims equal the block's as
+Mosaic's tiling rule requires.
 """
 from __future__ import annotations
 
@@ -18,6 +22,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import bits
 
@@ -29,27 +34,35 @@ def _words_per_block(block: int) -> int:
 
 
 def _pack_kernel(codes_ref, blen_ref, words_ref, nbits_ref, *, block: int):
-    codes = codes_ref[...]  # (block, 2) uint32
-    blen = blen_ref[...]  # (block,) int32
+    # codes_ref (1, 2*block) interleaved lo/hi words; blen_ref (1, block)
     wpb = _words_per_block(block)
 
-    def body(i, carry):
-        acc, off = carry
-        n = blen[i]
-        c0 = codes[i, 0] & bits.mask_bits(jnp.minimum(n, 32))
-        c1 = codes[i, 1] & bits.mask_bits(jnp.maximum(n - 32, 0))
-        w = off // 32
-        s = off % 32
-        lo, mid, hi = bits.code64_shift(c0, c1, s)
-        seg = jnp.stack([lo, mid, hi])
-        cur = jax.lax.dynamic_slice(acc, (w,), (3,))
-        acc = jax.lax.dynamic_update_slice(acc, cur | seg, (w,))
-        return acc, off + n
+    def zero(j, carry):
+        words_ref[0, j] = jnp.uint32(0)
+        return carry
 
-    acc0 = jnp.zeros((wpb + 2,), jnp.uint32)
-    acc, total = jax.lax.fori_loop(0, block, body, (acc0, jnp.int32(0)))
-    words_ref[...] = acc[:wpb][None, :]
-    nbits_ref[...] = jnp.full((1,), total, jnp.int32)
+    jax.lax.fori_loop(0, wpb, zero, 0)
+
+    def body(i, off):
+        n = blen_ref[0, i]
+        c0 = codes_ref[0, 2 * i] & bits.mask_bits(jnp.minimum(n, 32))
+        c1 = codes_ref[0, 2 * i + 1] & bits.mask_bits(jnp.maximum(n - 32, 0))
+        w = off // 32
+        lo, mid, hi = bits.code64_shift(c0, c1, off % 32)
+        words_ref[0, w] = words_ref[0, w] | lo
+
+        # a code ending in the block's last word spills nothing past it
+        @pl.when(w + 1 < wpb)
+        def _mid():
+            words_ref[0, w + 1] = words_ref[0, w + 1] | mid
+
+        @pl.when(w + 2 < wpb)
+        def _hi():
+            words_ref[0, w + 2] = words_ref[0, w + 2] | hi
+
+        return off + n
+
+    nbits_ref[0, 0] = jax.lax.fori_loop(0, block, body, jnp.int32(0))
 
 
 def pack_blocks(codes: jax.Array, bitlen: jax.Array, block: int = DEFAULT_BLOCK, interpret: bool = False):
@@ -62,20 +75,22 @@ def pack_blocks(codes: jax.Array, bitlen: jax.Array, block: int = DEFAULT_BLOCK,
     nblocks = n // block
     wpb = _words_per_block(block)
     kernel = functools.partial(_pack_kernel, block=block)
-    return pl.pallas_call(
+    words, nbits = pl.pallas_call(
         kernel,
         grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((block, 2), lambda i: (i, 0)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, wpb), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
+        in_specs=[smem_row(2 * block), smem_row(block)],
+        out_specs=[smem_row(wpb), smem_row(1)],
         out_shape=[
-            jax.ShapeDtypeStruct((nblocks, wpb), jnp.uint32),
-            jax.ShapeDtypeStruct((nblocks,), jnp.int32),
+            jax.ShapeDtypeStruct((nblocks, 1, wpb), jnp.uint32),
+            jax.ShapeDtypeStruct((nblocks, 1, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(codes, bitlen)
+    )(codes.reshape(nblocks, 1, 2 * block), bitlen.reshape(nblocks, 1, block))
+    return words.reshape(nblocks, wpb), nbits.reshape(nblocks)
+
+
+def smem_row(width: int) -> pl.BlockSpec:
+    """Grid step i's (1, width) row of a (nblocks, 1, width) array, in SMEM."""
+    return pl.BlockSpec(
+        (None, 1, width), lambda i: (i, 0, 0), memory_space=pltpu.SMEM
+    )

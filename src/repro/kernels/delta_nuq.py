@@ -7,8 +7,10 @@ inside the kernel while the whole working set stays in VMEM. Each grid step
 handles a (SUBLANES, T) tile; every substream starts from a raw reference
 sample, so tiles are independent and the grid scales across cores/chips.
 
-Used by the gradient compressor (error-feedback quantized all-reduce) and the
-ADPCM codec's batch path.
+Mosaic lowers neither a dynamic column slice nor a scatter, so step t reads
+its column as a one-hot lane reduction and writes it as a one-hot select
+over the tile (`_column`). The kernel mirrors the jnp scans in
+`kernels/ref.py`; no served path calls it yet.
 """
 from __future__ import annotations
 
@@ -31,29 +33,39 @@ def _encode_tile(x, qbits: int, dmax: float, mu: float):
     def quant(d):
         sign = (d < 0).astype(jnp.uint32)
         y = jnp.log1p(mu * jnp.abs(d) / dmax) / log1p_mu
-        mag = jnp.clip(jnp.round(y * levels), 0, levels).astype(jnp.uint32)
+        # via int32: Mosaic has no direct float <-> uint32 cast
+        mag = jnp.clip(jnp.round(y * levels), 0, levels).astype(jnp.int32).astype(jnp.uint32)
         return (sign << (qbits - 1)) | mag
 
     def dequant(c):
         sign = (c >> (qbits - 1)) & jnp.uint32(1)
-        mag = (c & jnp.uint32(levels)).astype(jnp.float32) / levels
+        mag = (c & jnp.uint32(levels)).astype(jnp.int32).astype(jnp.float32) / levels
         d = (jnp.power(1.0 + mu, mag) - 1.0) / mu * dmax
         return jnp.where(sign == 1, -d, d)
 
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, (S, T), 1)
+    xbits = jax.lax.bitcast_convert_type(x, jnp.int32)
+
     def body(t, carry):
         xhat, codes = carry
-        d = jnp.clip(x[:, t] - xhat, -dmax, dmax)
+        xt = jax.lax.bitcast_convert_type(_column(xbits, t_idx, t), jnp.float32)
+        d = jnp.clip(xt - xhat, -dmax, dmax)
         c = quant(d)
         xhat = xhat + dequant(c)
-        codes = codes.at[:, t].set(c)
+        codes = jnp.where(t_idx == t, c[:, None], codes)
         return xhat, codes
 
-    codes0 = jnp.zeros((S, T), jnp.uint32)
     # substream bootstrap: first sample is the raw (bitcast) fp32 reference
     xhat0 = x[:, 0]
-    codes0 = codes0.at[:, 0].set(jax.lax.bitcast_convert_type(x[:, 0], jnp.uint32))
-    xhat, codes = jax.lax.fori_loop(1, T, body, (xhat0, codes0))
+    codes0 = jnp.where(t_idx == 0, jax.lax.bitcast_convert_type(x, jnp.uint32), 0)
+    xhat, codes = jax.lax.fori_loop(1, T, body, (xhat0, codes0.astype(jnp.uint32)))
     return codes
+
+
+def _column(v, t_idx, t):
+    """Column `t` of the int32 tile `v` as a one-hot lane reduction (Mosaic
+    lowers no dynamic lane slice): exact, as every other term is zero."""
+    return jnp.sum(jnp.where(t_idx == t, v, 0), axis=1)
 
 
 def _encode_kernel(x_ref, codes_ref, *, qbits: int, dmax: float, mu: float):
@@ -67,18 +79,22 @@ def _decode_kernel(codes_ref, x_ref, *, qbits: int, dmax: float, mu: float):
 
     def dequant(c):
         sign = (c >> (qbits - 1)) & jnp.uint32(1)
-        mag = (c & jnp.uint32(levels)).astype(jnp.float32) / levels
+        mag = (c & jnp.uint32(levels)).astype(jnp.int32).astype(jnp.float32) / levels
         d = (jnp.power(1.0 + mu, mag) - 1.0) / mu * dmax
         return jnp.where(sign == 1, -d, d)
 
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, (S, T), 1)
+    cbits = jax.lax.bitcast_convert_type(codes, jnp.int32)
+
     def body(t, carry):
         xhat, out = carry
-        xhat = xhat + dequant(codes[:, t])
-        out = out.at[:, t].set(xhat)
+        ct = jax.lax.bitcast_convert_type(_column(cbits, t_idx, t), jnp.uint32)
+        xhat = xhat + dequant(ct)
+        out = jnp.where(t_idx == t, xhat[:, None], out)
         return xhat, out
 
     xhat0 = jax.lax.bitcast_convert_type(codes[:, 0], jnp.float32)  # raw reference
-    out0 = jnp.zeros((S, T), jnp.float32).at[:, 0].set(xhat0)
+    out0 = jnp.where(t_idx == 0, jax.lax.bitcast_convert_type(codes, jnp.float32), 0.0)
     _, out = jax.lax.fori_loop(1, T, body, (xhat0, out0))
     x_ref[...] = out
 

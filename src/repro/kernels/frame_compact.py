@@ -6,17 +6,20 @@ worst-case word buffer (`OW = 2*symbols + 2` uint32) of which only the
 per-block buffers into the two wire-shaped arrays a frame transfers:
 
   * `compact_blocks` — exclusive-prefix-sum offsets over the per-block used
-    word counts, then a gather-compaction of every block's live prefix into
-    one contiguous payload. One grid step owns one block; each step's
-    dynamic store starts at its word offset, and because blocks are visited
-    in stream order the (zero-masked) dead tail of step b is overwritten by
-    step b+1's live words — the sequential-grid analogue of the carry-free
+    word counts, then a compaction of every block's live prefix into one
+    contiguous payload. One grid step owns one block and copies its live
+    words to its word offset in the payload, which every step shares and
+    the first step zeroes — the sequential-grid analogue of the carry-free
     scatter in the jnp formulation (`bits.compact_payload`, the oracle).
   * `pack_meta7_blocks` — per-block 7-bit bitlen packing (`bits.pack_meta7`
     oracle): the per-symbol bit lengths leave the device at their wire
     width (7 bits/symbol) instead of 32. 7-bit fields span at most two
     adjacent words, so the in-block fold ORs a 2-word window per symbol,
     mirroring the bitpack kernel's 3-word fold.
+
+Both move single words to data-dependent offsets, which Mosaic lowers only
+for scalar memory: the blocks and the payload live in SMEM and the scalar
+core runs the loops, as in kernels/bitpack.py.
 
 As with the other kernels here, the executor's fused scans use the jnp
 formulations in `core/bits.py` (XLA fuses them into the scan dispatch); the
@@ -30,25 +33,32 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import bits
+from repro.kernels.bitpack import smem_row
+
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)  # whole array, every step
 
 
-def _compact_kernel(nw_ref, off_ref, words_ref, out_ref, *, ow: int):
+def _compact_kernel(nw_ref, off_ref, words_ref, out_ref, *, cap: int):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():  # the untouched tail beyond total_words must read as zero
-        out_ref[...] = jnp.zeros_like(out_ref)
+        def zero(j, carry):
+            out_ref[j] = jnp.uint32(0)
+            return carry
 
-    row = words_ref[...].reshape(-1)  # (OW,) uint32, this block's buffer
-    nw = nw_ref[i]
+        jax.lax.fori_loop(0, cap, zero, 0)
+
     off = off_ref[i]
-    # zero the dead tail so the final block leaves zeros beyond total_words;
-    # interior blocks' zeroed tails are overwritten by the next block's live
-    # prefix (stores land at strictly increasing offsets, grid in order)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, ow), 1).reshape(-1)
-    out_ref[pl.ds(off, ow)] = jnp.where(lane < nw, row, jnp.uint32(0))
+
+    def copy(j, carry):  # this block's live prefix, words_ref (1, OW)
+        out_ref[off + j] = words_ref[0, j]
+        return carry
+
+    jax.lax.fori_loop(0, nw_ref[i], copy, 0)
 
 
 def compact_blocks(
@@ -63,38 +73,40 @@ def compact_blocks(
     n, ow = words.shape
     nw, offs = bits.block_word_counts(nbits)
     cap = n * ow
-    kernel = functools.partial(_compact_kernel, ow=ow)
+    kernel = functools.partial(_compact_kernel, cap=cap)
     payload = pl.pallas_call(
         kernel,
         grid=(n,),
-        in_specs=[
-            pl.BlockSpec((n,), lambda i: (0,)),
-            pl.BlockSpec((n,), lambda i: (0,)),
-            pl.BlockSpec((1, ow), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((cap,), lambda i: (0,)),
+        in_specs=[_SMEM, _SMEM, smem_row(ow)],
+        out_specs=_SMEM,
         out_shape=jax.ShapeDtypeStruct((cap,), jnp.uint32),
         interpret=interpret,
-    )(nw, offs, words)
+    )(nw, offs, words.reshape(n, 1, ow))
     return payload, jnp.sum(nw).astype(jnp.int32)
 
 
 def _meta7_kernel(blen_ref, out_ref, *, symbols: int, mw: int):
-    bl = blen_ref[...].reshape(-1)  # (symbols,) int32
+    # blen_ref (1, symbols); out_ref (1, mw)
+    def zero(j, carry):
+        out_ref[0, j] = jnp.uint32(0)
+        return carry
 
-    def body(i, acc):
+    jax.lax.fori_loop(0, mw, zero, 0)
+
+    def body(i, carry):
         off = 7 * i
         w = off // 32
         s = off % 32
-        v = bl[i].astype(jnp.uint32) & jnp.uint32(0x7F)
-        lo = bits._safe_lshift(v, s)
-        hi = bits._safe_rshift(v, 32 - s)  # spill word (0 when s == 0)
-        cur = jax.lax.dynamic_slice(acc, (w,), (2,))
-        return jax.lax.dynamic_update_slice(acc, cur | jnp.stack([lo, hi]), (w,))
+        v = blen_ref[0, i].astype(jnp.uint32) & jnp.uint32(0x7F)
+        out_ref[0, w] = out_ref[0, w] | bits._safe_lshift(v, s)
 
-    acc0 = jnp.zeros((mw + 1,), jnp.uint32)
-    acc = jax.lax.fori_loop(0, symbols, body, acc0)
-    out_ref[...] = acc[:mw][None, :]
+        @pl.when(w + 1 < mw)
+        def _spill():  # the field's high bits (0 when it fits in word w)
+            out_ref[0, w + 1] = out_ref[0, w + 1] | bits._safe_rshift(v, 32 - s)
+
+        return carry
+
+    jax.lax.fori_loop(0, symbols, body, 0)
 
 
 def pack_meta7_blocks(bitlen: jax.Array, interpret: bool = False) -> jax.Array:
@@ -105,11 +117,12 @@ def pack_meta7_blocks(bitlen: jax.Array, interpret: bool = False) -> jax.Array:
     n, symbols = bitlen.shape
     mw = (7 * symbols + 31) // 32
     kernel = functools.partial(_meta7_kernel, symbols=symbols, mw=mw)
-    return pl.pallas_call(
+    packed = pl.pallas_call(
         kernel,
         grid=(n,),
-        in_specs=[pl.BlockSpec((1, symbols), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, mw), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, mw), jnp.uint32),
+        in_specs=[smem_row(symbols)],
+        out_specs=smem_row(mw),
+        out_shape=jax.ShapeDtypeStruct((n, 1, mw), jnp.uint32),
         interpret=interpret,
-    )(bitlen)
+    )(bitlen.reshape(n, 1, symbols))
+    return packed.reshape(n, mw)

@@ -1,9 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in `interpret=True` mode — the
+On the CPU backend the kernels execute in `interpret=True` mode — the
 kernel body runs under the Pallas interpreter for correctness validation; on
-TPU the same call sites compile to Mosaic. `interpret` resolves automatically
-from the backend.
+TPU the same call sites compile to Mosaic (tests/test_tpu_compile.py).
+`interpret` resolves automatically from the backend.
 """
 from __future__ import annotations
 

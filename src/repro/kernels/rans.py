@@ -16,9 +16,9 @@ tests/test_kernels.py):
     in constant-index-map refs, so all lanes start in parallel from the
     offset stream with no sequential carry between lanes.
 
-Frequency/cumulative/slot tables are looked up via one-hot
-broadcast-compare folds (vector-unit friendly; no dynamic gathers); the
-per-lane stream reads are 8 static dynamic-slices.
+Frequency/cumulative/slot tables and the per-lane stream reads are all
+one-hot broadcast-compare folds (vector-unit friendly): Mosaic lowers
+neither a dynamic gather nor a dynamic slice of a vector value.
 """
 from __future__ import annotations
 
@@ -30,12 +30,16 @@ from repro.core.entropy import N_LANES, PROB_BITS, PROB_SCALE, RANS_L
 
 
 def _lookup(table: jax.Array, idx: jax.Array, width: int) -> jax.Array:
-    """One-hot gather: table (width,), idx (N,) int32 -> (N,) table dtype."""
+    """One-hot gather: table (width,), idx (N,) int32 -> (N,) table dtype.
+    The fold sums in int32 (Mosaic reduces no unsigned type); exactly one
+    term per row is nonzero, so the sum is the entry bit for bit."""
     onehot = (
         jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], width), 1)
         == idx[:, None]
     )
-    return jnp.sum(jnp.where(onehot, table[None, :], 0), axis=1).astype(table.dtype)
+    t32 = jax.lax.bitcast_convert_type(table, jnp.int32)
+    got = jnp.sum(jnp.where(onehot, t32[None, :], 0), axis=1)
+    return jax.lax.bitcast_convert_type(got, table.dtype)
 
 
 def _enc_kernel(syms_ref, mask_ref, fr_ref, cum_ref, state_ref, flags_ref, vals_ref):
@@ -78,28 +82,37 @@ def encode_rows(
             jnp.zeros((0, N_LANES), jnp.int32),
             jnp.zeros((0, N_LANES), jnp.uint32),
         )
-    rev = lambda i: (t_rows - 1 - i, 0)  # noqa: E731 — reverse row order
-    return pl.pallas_call(
+    rev = _row_spec(lambda i: t_rows - 1 - i)  # rANS encodes backwards
+    states, flags, vals = pl.pallas_call(
         _enc_kernel,
         grid=(t_rows,),
         in_specs=[
-            pl.BlockSpec((1, N_LANES), rev),
-            pl.BlockSpec((1, N_LANES), rev),
+            rev,
+            rev,
             pl.BlockSpec((256,), lambda i: (0,)),
             pl.BlockSpec((256,), lambda i: (0,)),
         ],
-        out_specs=[
-            pl.BlockSpec((N_LANES,), lambda i: (0,)),
-            pl.BlockSpec((1, N_LANES), rev),
-            pl.BlockSpec((1, N_LANES), rev),
-        ],
+        out_specs=[pl.BlockSpec((N_LANES,), lambda i: (0,)), rev, rev],
         out_shape=[
             jax.ShapeDtypeStruct((N_LANES,), jnp.uint32),
-            jax.ShapeDtypeStruct((t_rows, N_LANES), jnp.int32),
-            jax.ShapeDtypeStruct((t_rows, N_LANES), jnp.uint32),
+            jax.ShapeDtypeStruct((t_rows, 1, N_LANES), jnp.int32),
+            jax.ShapeDtypeStruct((t_rows, 1, N_LANES), jnp.uint32),
         ],
         interpret=interpret,
-    )(syms.astype(jnp.int32), mask.astype(jnp.int32), fr, cum)
+    )(_rows(syms), _rows(mask), fr, cum)
+    return states, flags.reshape(t_rows, N_LANES), vals.reshape(t_rows, N_LANES)
+
+
+def _row_spec(row) -> pl.BlockSpec:
+    """One (1, N_LANES) row of a (T, 1, N_LANES) grid per step: the block's
+    last two dims equal the array's, which is what Mosaic's (8, 128)
+    tiling rule accepts for a block this narrow."""
+    return pl.BlockSpec((None, 1, N_LANES), lambda i: (row(i), 0, 0))
+
+
+def _rows(grid: jax.Array) -> jax.Array:
+    """(T, N_LANES) -> int32 (T, 1, N_LANES), the layout `_row_spec` tiles."""
+    return grid.astype(jnp.int32)[:, None, :]
 
 
 def _dec_kernel(
@@ -125,9 +138,7 @@ def _dec_kernel(
     stream = stream_ref[...]
     cap = stream.shape[0]
     pc = jnp.clip(p, 0, cap - 1)
-    w = jnp.concatenate(
-        [jax.lax.dynamic_slice(stream, (pc[j],), (1,)) for j in range(N_LANES)]
-    )
+    w = _lookup(stream, pc, cap)
     x3 = jnp.where(need, (x2 << jnp.uint32(16)) | w, x2)
     x_ref[...] = jnp.where(m, x3, x)
     p_ref[...] = p + need.astype(jnp.int32)
@@ -166,15 +177,15 @@ def decode_rows(
             pl.BlockSpec((PROB_SCALE,), lambda i: (0,)),
             pl.BlockSpec((N_LANES,), lambda i: (0,)),
             pl.BlockSpec((N_LANES,), lambda i: (0,)),
-            pl.BlockSpec((1, N_LANES), lambda i: (i, 0)),
+            _row_spec(lambda i: i),
         ],
         out_specs=[
-            pl.BlockSpec((1, N_LANES), lambda i: (i, 0)),
+            _row_spec(lambda i: i),
             pl.BlockSpec((N_LANES,), lambda i: (0,)),
             pl.BlockSpec((N_LANES,), lambda i: (0,)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((t_rows, N_LANES), jnp.uint32),
+            jax.ShapeDtypeStruct((t_rows, 1, N_LANES), jnp.uint32),
             jax.ShapeDtypeStruct((N_LANES,), jnp.uint32),
             jax.ShapeDtypeStruct((N_LANES,), jnp.int32),
         ],
@@ -186,6 +197,6 @@ def decode_rows(
         lut,
         states.astype(jnp.uint32),
         offsets.astype(jnp.int32),
-        mask.astype(jnp.int32),
+        _rows(mask),
     )
-    return syms
+    return syms.reshape(t_rows, N_LANES)

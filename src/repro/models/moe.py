@@ -21,7 +21,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.models import partition
 
 
@@ -169,7 +168,7 @@ def moe_ffn(params, cfg, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
         from jax.sharding import PartitionSpec as P
 
         axis_names = frozenset(dax if isinstance(dax, tuple) else (dax,))
-        buf, e_idx, slot = compat.shard_map(
+        buf, e_idx, slot = jax.shard_map(
             disp_local,
             in_specs=(P(dax, None), P(dax, None)),
             out_specs=(P(None, dax, None), P(dax), P(dax)),
@@ -200,7 +199,7 @@ def moe_ffn(params, cfg, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
         from jax.sharding import PartitionSpec as P
 
         axis_names = frozenset(dax if isinstance(dax, tuple) else (dax,))
-        yt = compat.shard_map(
+        yt = jax.shard_map(
             comb_local,
             in_specs=(P(None, dax, None), P(dax), P(dax), P(dax, None)),
             out_specs=P(dax, None),

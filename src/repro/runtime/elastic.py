@@ -20,8 +20,9 @@ import dataclasses
 from typing import Any, Optional, Tuple
 
 import jax
+import numpy as np
+from jax.sharding import AxisType, Mesh
 
-from repro import compat
 from repro.models import partition
 from repro.runtime import sharding as shpol
 
@@ -74,9 +75,18 @@ def make_mesh_for(n_devices: int, devices=None, profile: str = "lm"):
     (healthy) device list — required when meshing a strict subset of the
     visible devices, e.g. after a device loss."""
     shape, names = plan_mesh(n_devices, profile=profile)
-    if devices is None and n_devices != jax.device_count():
+    # Auto axes (jax.make_mesh defaults to Explicit ones): a sharded wave
+    # output stays indexable member by member
+    axis_types = (AxisType.Auto,) * len(names)
+    if devices is None and n_devices == jax.device_count():
+        # every visible device: let jax lay the mesh out along the topology
+        return jax.make_mesh(shape, names, axis_types), logical_mapping(names)
+    if devices is None:
         devices = jax.devices()[:n_devices]
-    return compat.make_mesh(shape, names, devices=devices), logical_mapping(names)
+    # a strict subset (e.g. the survivors of a device loss) is laid out as
+    # given: a topology-ordered builder may refuse or reorder it
+    mesh = Mesh(np.asarray(devices, dtype=object).reshape(shape), names, axis_types)
+    return mesh, logical_mapping(names)
 
 
 def reshard(tree: Any, logical_specs: Any, mesh, mapping: dict) -> Any:

@@ -39,6 +39,7 @@ from typing import Any, Dict, List, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core import bits, metrics
 from repro.core.algorithms import Codec
@@ -71,6 +72,9 @@ from repro.runtime.fault import (
 # NOTE: repro.runtime.elastic (the fleet mesh planner) is imported lazily in
 # `ServerCore.__init__` — it pulls the LM sharding policy module tree in, and
 # only fleet-mode servers need it.
+
+#: gather a gang wave's live word prefixes into one contiguous payload
+_compact_wave = jax.jit(bits.compact_payload)
 
 
 @dataclasses.dataclass
@@ -294,7 +298,6 @@ class StreamSession:
         self._egress_blocks: List[tuple] = []
         self._egress_values: List[np.ndarray] = []
         self._egress_cache: Optional[tuple] = None  # (n_blocks, fidelity triple)
-        self._decompressor: Optional[DecompressionPipeline] = None
         # ---- adaptive tier state (controller + tiers; DESIGN.md §16) ------
         #: the controller observing flush outcomes and picking rungs; None
         #: for ordinary (static) sessions
@@ -458,7 +461,6 @@ class StreamSession:
         self._dict_codecs[trained.dict_id] = pipe.codec
         self.state = pipe.init_state()
         self._signature = None
-        self._decompressor = None  # rebuilt lazily against the new seed
         self.dict_swaps += 1
         self._warm()
         if self.signature_listener is not None:
@@ -839,11 +841,7 @@ class StreamSession:
                     )
                     self._tier_decomp[self.active_tier or ""] = decomp
             else:
-                if self._decompressor is None:
-                    self._decompressor = DecompressionPipeline(
-                        self.config, codec=self.pipeline.codec
-                    )
-                decomp = self._decompressor
+                decomp = self.pipeline.decompressor()
             dec = decomp.decompress(frame)
             decoded.append(dec.values)
             feds.append(
@@ -1002,9 +1000,9 @@ class ServerCore:
                     raise ValueError(f"mesh must be >= 1 device, got {n}")
                 if n > avail:
                     raise ValueError(
-                        f"mesh={n} exceeds the {avail} visible device(s); "
-                        "launch with XLA_FLAGS=--xla_force_host_platform_"
-                        f"device_count={n} or shrink the mesh"
+                        f"mesh={n} exceeds the {avail} visible "
+                        f"{jax.devices()[0].platform} device(s); shrink the "
+                        f"mesh to <= {avail}"
                     )
                 self.fleet = _ElasticSession(n_devices=n, profile="cstream")
             if tuple(self.fleet.mesh.axis_names) != ("data",):
@@ -1146,6 +1144,12 @@ class ServerCore:
             }
         )
         self.fleet.resize(len(healthy), devices=healthy)
+        # a member's committed state is replicated over the mesh it last ran
+        # on; move every state onto the survivors, so the replayed wave never
+        # stacks arrays committed to two device sets
+        replicated = NamedSharding(self.fleet.mesh, PartitionSpec())
+        for sess in self.sessions.values():
+            sess.state = jax.device_put(sess.state, replicated)
         for s, gp in self._gang_plans.items():
             self._fleet_plans[s] = plan_fleet(gp, self.fleet.n_devices)
         if self.heartbeat is not None:
@@ -1163,15 +1167,13 @@ class ServerCore:
         On a fleet server the stacked session axis additionally shards over
         the mesh's data axis: the wave is padded to a multiple of the mesh
         width by replicating member 0 (pad outputs are discarded before
-        commit), each shard compresses its local session slice, and egress
-        compaction stays per-shard — commits slice exact live word prefixes
-        out of the sharded rows, so D2H stays wire-width per member.
+        commit) and each shard compresses its local session slice.
 
         Egress scatter is compacted (DESIGN.md §13): only the per-member
-        bit counts always cross device->host; each egress member's commit
-        then slices its exact live word prefix (plus wire-width packed
-        metadata when the wave ran the meta7 dispatch) out of the device
-        rows — non-egress waves fetch no payload at all."""
+        bit counts always cross device->host; the egress members' live word
+        prefixes are gathered on the device and fetched in one transfer
+        (`_fetch_wave_egress`), then sliced per member on the host —
+        non-egress waves fetch no payload at all."""
         stats = self._stats.get(sig)
         if len(wave) == 1:
             s, req = wave[0]
@@ -1193,24 +1195,26 @@ class ServerCore:
             pad = (-len(wave)) % self.fleet.n_devices
             members = wave + [wave[0]] * pad
         states = pipe.stack_states([s.state for s, _ in members])
-        blocks = jnp.asarray(
-            np.stack([req.values.reshape(lanes, -1) for _, req in members])
-        )
-        masks = jnp.asarray(
-            np.stack([req.mask.reshape(lanes, -1) for _, req in members])
-        )
+        blocks = np.stack([req.values.reshape(lanes, -1) for _, req in members])
+        masks = np.stack([req.mask.reshape(lanes, -1) for _, req in members])
+        if mesh is None:
+            blocks, masks = jnp.asarray(blocks), jnp.asarray(masks)
+        else:  # each device receives its own slice of the wave directly
+            sharding = NamedSharding(mesh, PartitionSpec("data"))
+            blocks, masks = jax.device_put((blocks, masks), sharding)
         states, words, tbs, metas, wall = pipe.gang_step(
             states, blocks, masks, meta7=meta7, mesh=mesh
         )
         tb_np = np.asarray(tbs)
         cost = wall / len(wave)  # the dispatch is shared; so is its cost
+        segments, metas_np = self._fetch_wave_egress(pipe, wave, words, tb_np, metas)
         for i, (s, req) in enumerate(wave):  # pad slots sit past len(wave)
             s.commit(
                 req,
                 pipe.unstack_state(states, i),
-                words[i],
+                segments[i] if segments[i] is not None else words[i],
                 int(tb_np[i]),
-                metas[i],
+                metas_np[i] if metas_np is not None else metas[i],
                 cost,
                 meta_packed=meta7,
             )
@@ -1224,6 +1228,45 @@ class ServerCore:
             stats.sessions_dispatched += len(wave)
             stats.max_wave = max(stats.max_wave, len(wave))
             stats.padded_slots += pad
+
+    @staticmethod
+    def _fetch_wave_egress(pipe, wave, words, tb_np, metas):
+        """Move a wave's egress output to the host in one transfer each.
+
+        Compacted egress members' live word prefixes are gathered into one
+        contiguous device payload, whose prefix is fetched at the next
+        power-of-two length and sliced per member on the host: a device
+        slice of data-dependent length compiles one program per length, a
+        power-of-two one a handful per signature. The metadata rows are
+        fetched once for the wave. Returns (per-member host segments, None
+        where the member takes its device row; host metadata rows or
+        None)."""
+        live = np.array(
+            [
+                (int(tb_np[i]) + 31) // 32 if s.egress and s._compact else 0
+                for i, (s, _) in enumerate(wave)
+            ],
+            np.int64,
+        )
+        segments: List[Optional[np.ndarray]] = [None] * len(wave)
+        if not any(s.egress for s, _ in wave):
+            return segments, None
+        n_rows = words.shape[0]
+        nbits = np.zeros(n_rows, np.int32)
+        nbits[: len(wave)] = np.where(live > 0, tb_np[: len(wave)], 0)
+        total = int(live.sum())
+        payload, _ = _compact_wave(words, jnp.asarray(nbits))
+        fetch = min(1 << max(total - 1, 0).bit_length(), payload.shape[0])
+        host = with_backoff(lambda: np.asarray(payload[:fetch]))
+        offsets = np.cumsum(live) - live
+        for i, (s, _) in enumerate(wave):
+            if s.egress and s._compact:
+                segments[i] = host[offsets[i] : offsets[i] + live[i]]
+        metas_np = with_backoff(lambda: np.asarray(metas))[: len(wave)]
+        # rows of non-egress members crossed too (pad slots stay behind)
+        n_egress = sum(1 for s, _ in wave if s.egress)
+        pipe.d2h_meta_bytes += (len(wave) - n_egress) * metas_np[0].nbytes
+        return segments, metas_np
 
     # -------------------------------------------------------------- admit
     def admit(
