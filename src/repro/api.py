@@ -91,6 +91,7 @@ from repro.runtime.server import (
     SignatureStats,
     StreamSession,
 )
+from repro.runtime import spans
 
 __all__ = [
     "JobSpec",
@@ -1318,7 +1319,9 @@ class StreamHandle:
             return []
         # sealed adaptive tier segments + the open segment; static sessions
         # yield exactly their one closing frame
-        return self._session.egress_frames()
+        frames = self._session.egress_frames()
+        spans.count("handle.frames_delivered", len(frames))
+        return frames
 
     # ---------------------------------------------------------------- report
     def report(self) -> JobReport:
@@ -1694,6 +1697,7 @@ class Dispatcher:
         ]
 
     # ------------------------------------------------------------------ run
+    @spans.spanned("dispatcher.run")
     def run(self) -> Optional[ServerReport]:
         """Replay every handle's staged feed in merged arrival order through
         the serving runtime; returns the ServerReport (None if nothing was

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.runtime import spans
+
 U32 = jnp.uint32
 # numpy scalars: plain literals to the tracer (never captured-constant arrays,
 # which Pallas kernels reject)
@@ -650,6 +652,7 @@ class Frame:
         )
 
     # ------------------------------------------------------- entropy stage --
+    @spans.spanned("egress.rans")
     def apply_entropy(self) -> "Frame":
         """Attach the rANS stage-2 blob (DESIGN.md §15), in place.
 
@@ -723,6 +726,7 @@ class Frame:
             payload_sec,
         )
 
+    @spans.spanned("frame.to_bytes")
     def to_bytes(self) -> bytes:
         if self.integrity is not None and self.integrity not in INTEGRITY_KINDS:
             raise ValueError(

@@ -61,6 +61,7 @@ from repro.core.strategies import (
     StateStrategy,
     plan_execution,
 )
+from repro.runtime import spans
 
 
 #: scan length used when force-fusing a stream whose plan is per-block
@@ -816,12 +817,14 @@ class CompressionPipeline(BlockedExecutor):
         if key not in self._warmed:
             jax.block_until_ready(fn(states, blocks, masks))
             self._warmed.add(key)
-        t0 = time.perf_counter()
-        self.dispatches += 1
-        states, words, total_bits, bitlen = jax.block_until_ready(
-            fn(states, blocks, masks)
-        )
-        return states, words, total_bits, bitlen, time.perf_counter() - t0
+        with spans.span("wave.step"):
+            t0 = time.perf_counter()
+            self.dispatches += 1
+            states, words, total_bits, bitlen = jax.block_until_ready(
+                fn(states, blocks, masks)
+            )
+            wall = time.perf_counter() - t0
+        return states, words, total_bits, bitlen, wall
 
     def _gang_scan_body(self, states: Any, blks: jax.Array):
         """Scan body for offline gang runs: blks is (S, L, B) — the blocks
@@ -1456,6 +1459,7 @@ class CompressionPipeline(BlockedExecutor):
         """Single authority for frame marshalling: codec id and lane count
         come from this pipeline's config, callers only supply the block
         geometry and the (words, nbits, bitlen, valid) entries."""
+        spans.count("egress.frames_marshalled")
         return self._maybe_entropy(bits.build_frame(
             codec_id=WIRE_CODEC_IDS[self.codec.name],
             lanes=self.config.lanes,
@@ -1484,6 +1488,7 @@ class CompressionPipeline(BlockedExecutor):
         """`marshal_frame`'s compacted twin: codec id and lane count still
         come from this pipeline's config; the caller hands over the
         already-wire-shaped payload/metadata (`Frame.from_compacted`)."""
+        spans.count("egress.frames_marshalled")
         return self._maybe_entropy(bits.Frame.from_compacted(
             codec_id=WIRE_CODEC_IDS[self.codec.name],
             lanes=self.config.lanes,
@@ -1624,6 +1629,7 @@ class DecompressionPipeline(BlockedExecutor):
         return shapes, block_arrays
 
     # ------------------------------------------------------------ decompress
+    @spans.spanned("collector.decode")
     def decompress(self, frame: bits.Frame, warmup: bool = True) -> DecompressionResult:
         """Reconstruct a frame's stream through the fused chunked executor.
 
@@ -1632,6 +1638,7 @@ class DecompressionPipeline(BlockedExecutor):
         and every later call refuses until :meth:`reset_quarantine` — a
         poisoned session must not silently keep emitting values from a
         stream whose framing it no longer trusts."""
+        spans.count("decode.frames")
         self._check_quarantine()
         try:
             return self._decompress(frame, warmup=warmup)
@@ -1647,6 +1654,7 @@ class DecompressionPipeline(BlockedExecutor):
             self.quarantined = err
             raise err from exc
 
+    @spans.spanned("collector.ingest")
     def ingest(self, buf: Union[bytes, bytearray, memoryview]) -> DecompressionResult:
         """Parse raw wire bytes and decode them in one step.
 
@@ -1660,6 +1668,7 @@ class DecompressionPipeline(BlockedExecutor):
         except bits.FrameError as err:
             self.quarantined = err
             raise
+        spans.count("collector.frames_ingested")
         return self.decompress(frame)
 
     def reset_quarantine(self) -> None:

@@ -68,6 +68,7 @@ from repro.runtime.fault import (
     HeartbeatMonitor,
     with_backoff,
 )
+from repro.runtime import spans
 
 # NOTE: repro.runtime.elastic (the fleet mesh planner) is imported lazily in
 # `ServerCore.__init__` — it pulls the LM sharding policy module tree in, and
@@ -635,12 +636,13 @@ class StreamSession:
         """Compress one flush snapshot inline (the solo dispatch path)."""
         block = jnp.asarray(req.values.reshape(self.lanes, -1))
         mask_dev = jnp.asarray(req.mask.reshape(self.lanes, -1))
-        t0 = time.perf_counter()
-        self.pipeline.dispatches += 1
-        state, words, total_bits, meta = jax.block_until_ready(
-            self._flush_step_fn()(self.state, block, mask_dev)
-        )
-        cost = time.perf_counter() - t0
+        with spans.span("wave.step"):
+            t0 = time.perf_counter()
+            self.pipeline.dispatches += 1
+            state, words, total_bits, meta = jax.block_until_ready(
+                self._flush_step_fn()(self.state, block, mask_dev)
+            )
+            cost = time.perf_counter() - t0
         return self.commit(
             req, state, words, total_bits, meta, cost,
             meta_packed=self.egress and self._meta_packed,
@@ -717,6 +719,7 @@ class StreamSession:
         return rec
 
     # ------------------------------------------------------------- egress
+    @spans.spanned("egress.frame")
     def egress_frame(self) -> bits.Frame:
         """Close the session's bitstream into one wire-format frame.
 
@@ -789,6 +792,7 @@ class StreamSession:
             packed_meta=packed_meta,
         )
 
+    @spans.spanned("report.fidelity")
     def egress_fidelity(self):
         """Decode the session's frame and check the fidelity contract.
 
@@ -1155,6 +1159,7 @@ class ServerCore:
         if self.heartbeat is not None:
             self.heartbeat.beat()  # recovery progress counts as liveness
 
+    @spans.spanned("server.wave")
     def _run_wave(
         self, sig: tuple, wave: List[Tuple[StreamSession, FlushRequest]]
     ) -> None:
@@ -1412,7 +1417,14 @@ class ServerCore:
         for t, v, ts in zip(topics, values, tss):
             if len(v) != len(ts):
                 raise ValueError(f"{t}: {len(v)} values vs {len(ts)} timestamps")
+        self._replay(topics, values, tss)
+        return self.report(topics)
 
+    @spans.spanned("server.replay")
+    def _replay(
+        self, topics: List[str], values: List[np.ndarray], tss: List[np.ndarray]
+    ) -> None:
+        """`run`'s walk of the merged arrival order and its final drain."""
         # merged arrival order (stable: ties keep topic order)
         all_ts = np.concatenate(tss) if tss else np.zeros(0)
         topic_idx = np.concatenate(
@@ -1467,8 +1479,9 @@ class ServerCore:
 
         # walk the merged order in runs of equal topic so full batches move
         # through offer_many; timeout flushes fire as the clock advances
-        i, n = 0, len(order)
+        i, n, runs = 0, len(order), 0
         while i < n:
+            runs += 1
             j = i
             tpi = topic_idx[order[i]]
             while j < n and topic_idx[order[j]] == tpi:
@@ -1491,10 +1504,11 @@ class ServerCore:
                 s.flush(s.flush_deadline)
         if self.gang:
             self._dispatch_all(final=True)
-
-        return self.report(topics)
+        spans.count("replay.tuples", n)
+        spans.count("replay.runs", runs)
 
     # ------------------------------------------------------------- report
+    @spans.spanned("server.report")
     def report(self, topics: Optional[List[str]] = None) -> ServerReport:
         topics = sorted(self.sessions) if topics is None else topics
         sess = [self.sessions[t] for t in topics]
