@@ -31,7 +31,6 @@ wall clock measures only compression compute, never the synthetic waiting.
 from __future__ import annotations
 
 import dataclasses
-import heapq
 import time
 import warnings
 from typing import Any, Dict, List, Optional, Tuple, Union
@@ -76,6 +75,106 @@ from repro.runtime import spans
 
 #: gather a gang wave's live word prefixes into one contiguous payload
 _compact_wave = jax.jit(bits.compact_payload)
+
+
+def batch_end(
+    tss: np.ndarray,
+    start: int,
+    count: int,
+    deadline: float,
+    capacity: int,
+    cut: Optional[int] = None,
+) -> Tuple[int, Optional[str]]:
+    """Where a micro-batch closes in one topic's ascending arrivals: the
+    single definition of a batch boundary, shared by
+    `StreamSession.offer_many` and the server's event replay.
+
+    The open batch holds `count` tuples and its timer fires at `deadline`
+    (oldest arrival + timeout). It takes the tuples from `tss[start]` on
+    that arrive at or before the deadline (`side="right"`), up to
+    `capacity`. A full batch closes by size, stamped at its last tuple; the
+    first later tuple, or a timer pop that the merged order sees before
+    the topic's tuple `cut`, closes it by timeout, stamped at the
+    deadline. Returns `(end, closed)`: `tss[start:end]` join the batch and
+    `closed` is "size", "timeout", or None while the batch stays open."""
+    space = capacity - count
+    end = start + int(np.searchsorted(tss[start : start + space], deadline, side="right"))
+    if count == 0:
+        end = max(end, start + 1)  # a fresh batch's first tuple opens it
+    if cut is not None and cut < end:
+        return cut, "timeout"
+    if count + end - start >= capacity:
+        return end, "size"
+    if end < len(tss) or end == cut:
+        return end, "timeout"
+    return end, None
+
+
+def _flush_events(
+    sess: List["StreamSession"], values: List[np.ndarray], tss: List[np.ndarray]
+) -> Tuple[List[tuple], List[Tuple[np.ndarray, np.ndarray]], List[tuple], np.ndarray]:
+    """The flushes of one `ServerCore.run`, computed from the merged order.
+
+    The merged arrival order (a stable argsort: ties keep topic order)
+    falls into runs of equal topic, and the clock advances to each run's
+    end timestamp. Each topic's batches come from `batch_end` over its own
+    arrivals: a batch closes by size inside the run of its filling tuple,
+    by its own next tuple past the deadline inside that tuple's run, or by
+    its timer at the first run end at or past the deadline, counted from
+    the run that opened it — whichever the merged order reaches first.
+
+    Returns the events as `(run, phase, order key, topic, start, end,
+    stamp)` sorted as they act: by run, the run's own flushes (phase 0, in
+    feed order) before timer pops (phase 1, in (deadline, topic) order);
+    each topic's feed in merged order; the batches left open at the end of
+    the feeds as `(topic, start, end)`; and the run end timestamps."""
+    lens = [len(ts) for ts in tss]
+    n = sum(lens)
+    all_ts = np.concatenate(tss) if tss else np.zeros(0)
+    all_vals = np.concatenate(values) if values else np.zeros(0, np.uint32)
+    order = np.argsort(all_ts, kind="stable")
+    merged_topic = np.repeat(np.arange(len(tss), dtype=np.int32), lens)[order]
+    run_starts = np.flatnonzero(merged_topic[1:] != merged_topic[:-1]) + 1
+    run_of = np.zeros(n, np.int64)
+    run_of[run_starts] = 1
+    run_of = np.cumsum(run_of)  # run index of each merged position
+    run_end = all_ts[order[np.append(run_starts - 1, n - 1)]] if n else all_ts
+    n_runs = len(run_end)
+    # each topic's merged positions, ascending
+    by_topic = np.split(
+        np.argsort(merged_topic, kind="stable"), np.cumsum(lens)[:-1]
+    ) if tss else []
+
+    events: List[tuple] = []
+    feeds: List[Tuple[np.ndarray, np.ndarray]] = []
+    residual: List[tuple] = []
+    for k, (s, mpos) in enumerate(zip(sess, by_topic)):
+        ts_k = all_ts[order[mpos]]
+        runs_k = run_of[mpos]
+        feeds.append((all_vals[order[mpos]], ts_k))
+        i, count = 0, s.buffered
+        while i < len(ts_k) or count:
+            if count:  # a batch left open before this feed
+                deadline = s.flush_deadline
+                opened = int(runs_k[0]) if len(ts_k) else n_runs
+            else:
+                deadline = float(ts_k[i]) + s.flush_timeout_s
+                opened = int(runs_k[i])
+            pop = max(int(np.searchsorted(run_end, deadline, side="left")), opened)
+            cut = int(np.searchsorted(runs_k, pop, side="right")) if pop < n_runs else None
+            end, closed = batch_end(ts_k, i, count, deadline, s.capacity, cut)
+            if closed is None:
+                residual.append((k, i, end))
+                break
+            if closed == "size":
+                events.append((int(runs_k[end - 1]), 0, i, k, i, end, float(ts_k[end - 1])))
+            elif end == cut:  # the timer pops at a run end
+                events.append((pop, 1, (deadline, k), k, i, end, deadline))
+            else:  # the topic's next tuple arrives past the deadline
+                events.append((int(runs_k[end]), 0, i, k, i, end, deadline))
+            i, count = end, 0
+    events.sort(key=lambda e: e[:3])
+    return events, feeds, residual, run_end
 
 
 @dataclasses.dataclass
@@ -501,8 +600,8 @@ class StreamSession:
     def flush_deadline(self) -> Optional[float]:
         """When the buffered batch's flush timer fires: oldest arrival +
         timeout. None with nothing buffered. The ONE definition of the
-        deadline — `poll`, the server's drain path, and tests all read this
-        instead of poking `_arrivals`."""
+        deadline — `offer_many`, the server's replay and drain, and tests
+        all read this instead of poking `_arrivals`."""
         if not self._count:
             return None
         return float(self._arrivals[0]) + self.flush_timeout_s
@@ -523,65 +622,48 @@ class StreamSession:
             )
         return self._signature
 
-    def due(self, now: float) -> bool:
-        """Size reached, or the oldest buffered tuple timed out."""
-        if self._count >= self.capacity:
-            return True
-        deadline = self.flush_deadline
-        return deadline is not None and now >= deadline
-
-    def poll(self, now: float) -> Optional[FlushRecord]:
-        """Fire the flush timer if it is due by `now`. The flush is stamped
-        at the DEADLINE (oldest arrival + timeout), not at `now` — the clock
-        may have advanced well past the deadline before the server polled
-        (e.g. another topic's long arrival run), and the batch's tuples
-        stopped waiting when the timer fired."""
-        if not self.due(now):
-            return None
-        return self.flush(now=min(now, self.flush_deadline))
+    def _buffer(self, values: np.ndarray, tss: np.ndarray) -> None:
+        """Append one contiguous slice of the feed to the open batch."""
+        m = len(values)
+        self._values[self._count : self._count + m] = values
+        self._arrivals[self._count : self._count + m] = tss
+        self._count += m
 
     def offer(self, value: int, ts: float) -> Optional[FlushRecord]:
-        """Buffer one tuple; flush (and return the record) when full."""
-        self._values[self._count] = value
-        self._arrivals[self._count] = ts
-        self._count += 1
-        if self._count >= self.capacity:
-            return self.flush(now=ts)
-        return None
+        """Buffer one tuple: `offer_many` of a one-tuple feed, returning the
+        record of the flush it triggered (inline path only)."""
+        recs = self.offer_many(
+            np.array([value], np.uint32), np.array([ts], np.float64)
+        )
+        return recs[-1] if recs else None
 
     def offer_many(self, values: np.ndarray, tss: np.ndarray) -> List[FlushRecord]:
-        """Buffer a run of tuples (same topic, ascending timestamps),
+        """Buffer a feed of tuples (same topic, ascending timestamps),
         flushing whenever a batch fills OR a batch's deadline (oldest
         arrival + timeout) passes before the next tuple arrives.
 
-        Returns the records of flushes executed inline; in gang mode
-        (`flush_sink` set) flushes only enqueue, so the list is empty and
-        their records land in `self.flushes` at gang dispatch."""
+        `batch_end` finds each boundary, and each batch is buffered as one
+        contiguous slice of the feed. Returns the records of flushes
+        executed inline; in gang mode (`flush_sink` set) flushes only
+        enqueue, so the list is empty and their records land in
+        `self.flushes` at gang dispatch."""
         out: List[FlushRecord] = []
-
-        def _flushed(rec: Optional[FlushRecord]) -> None:
-            if rec is not None:
-                out.append(rec)
-
         i, n = 0, len(values)
         while i < n:
-            if self._count == 0:
-                deadline = float(tss[i]) + self.flush_timeout_s
-            else:
-                deadline = self.flush_deadline
-                if float(tss[i]) > deadline:  # timer fired before this tuple
-                    _flushed(self.flush(now=deadline))
-                    continue
-            space = self.capacity - self._count
-            # tuples that arrive before the current batch's deadline join it
-            take = int(np.searchsorted(tss[i : i + space], deadline, side="right"))
-            take = max(take, 1)  # tss[i] <= deadline by construction
-            self._values[self._count : self._count + take] = values[i : i + take]
-            self._arrivals[self._count : self._count + take] = tss[i : i + take]
-            self._count += take
-            i += take
-            if self._count >= self.capacity:
-                _flushed(self.flush(now=float(tss[i - 1])))
+            deadline = (
+                self.flush_deadline
+                if self._count
+                else float(tss[i]) + self.flush_timeout_s
+            )
+            end, closed = batch_end(tss, i, self._count, deadline, self.capacity)
+            self._buffer(values[i:end], tss[i:end])
+            i = end
+            if closed is not None:
+                rec = self.flush(
+                    now=float(tss[end - 1]) if closed == "size" else deadline
+                )
+                if rec is not None:
+                    out.append(rec)
         return out
 
     # -------------------------------------------------------------- flush
@@ -1405,9 +1487,10 @@ class ServerCore:
     def run(self, feeds: Dict[str, Tuple[np.ndarray, np.ndarray]]) -> ServerReport:
         """Replay per-topic (values, arrival_timestamps) in merged time order.
 
-        Tuples are offered to their session as their timestamps fire; any
-        session whose oldest buffered tuple exceeds its flush timeout is
-        flushed as the simulated clock passes the deadline."""
+        The outcome is that of offering every tuple to its session as its
+        timestamp fires and flushing any session whose oldest buffered
+        tuple exceeds its flush timeout as the simulated clock passes the
+        deadline; `_replay` computes it event by event."""
         unknown = set(feeds) - set(self.sessions)
         if unknown:
             raise KeyError(f"feeds for unadmitted topics: {sorted(unknown)}")
@@ -1424,18 +1507,21 @@ class ServerCore:
     def _replay(
         self, topics: List[str], values: List[np.ndarray], tss: List[np.ndarray]
     ) -> None:
-        """`run`'s walk of the merged arrival order and its final drain."""
-        # merged arrival order (stable: ties keep topic order)
-        all_ts = np.concatenate(tss) if tss else np.zeros(0)
-        topic_idx = np.concatenate(
-            [np.full(len(ts), i, np.int32) for i, ts in enumerate(tss)]
-        ) if tss else np.zeros(0, np.int32)
-        within = np.concatenate(
-            [np.arange(len(ts), dtype=np.int64) for ts in tss]
-        ) if tss else np.zeros(0, np.int64)
-        order = np.argsort(all_ts, kind="stable")
+        """`run`'s event walk of the merged arrival order, and its drain.
 
+        Nothing but a flush or a gang quantum edge changes any state, so
+        the walk visits only the runs of equal topic where one acts: the
+        flushes `_flush_events` finds, then the run's gang edge poll. A
+        signature's edge, set at the first poll that finds its queue
+        non-empty as `(floor(now / q) + 1) * q`, fires at the first run
+        end at or past it. Backpressure waves, breakers, tier switches,
+        dictionary swaps and fleet waves therefore see the calls, in the
+        order, that offering every run in turn would make. Each batch is
+        buffered as one slice of its topic's feed just before it flushes."""
         sess = [self.sessions[t] for t in topics]
+        events, feeds, residual, run_end = _flush_events(sess, values, tss)
+        n_runs = len(run_end)
+
         # gang mode: collect flush snapshots between quantum edges; fire a
         # signature's gang dispatch whenever the simulated clock crosses its
         # next edge. Quanta come from the signature's GangPlan (half its
@@ -1463,49 +1549,52 @@ class ServerCore:
                     self._dispatch_signature(sig)
                     next_edges[sig] = (np.floor(now / q_s) + 1.0) * q_s
 
-        # deadline heap: only sessions whose flush timer can actually fire
-        # are examined per clock step. Entries are (deadline, topic index)
-        # pushed whenever a session buffers; stale entries (the batch
-        # already flushed, so the live deadline moved) are dropped on pop.
-        # Replaces the poll-every-session sweep, which made the replay
-        # quadratic in the session count — at 10k+ fleet sessions that
-        # sweep WAS the server.
-        pending: List[Tuple[float, int]] = []
+        def _next_edge_run(last: int) -> int:
+            """The first run after `last` whose end polls a non-empty
+            queue into an action: setting its edge, or firing it."""
+            nxt = n_runs
+            for sig, q in self._queues.items():
+                if q:
+                    edge = next_edges.get(sig)
+                    if edge is None:
+                        return last + 1
+                    at = int(np.searchsorted(run_end, edge, side="left"))
+                    nxt = min(nxt, max(at, last + 1))
+            return nxt
 
-        def _note(k: int) -> None:
-            d = sess[k].flush_deadline
-            if d is not None:
-                heapq.heappush(pending, (d, k))
-
-        # walk the merged order in runs of equal topic so full batches move
-        # through offer_many; timeout flushes fire as the clock advances
-        i, n, runs = 0, len(order), 0
-        while i < n:
-            runs += 1
-            j = i
-            tpi = topic_idx[order[i]]
-            while j < n and topic_idx[order[j]] == tpi:
-                j += 1
-            run_idx = within[order[i:j]]
-            now = float(all_ts[order[j - 1]])
-            sess[tpi].offer_many(values[tpi][run_idx], tss[tpi][run_idx])
-            _note(tpi)
-            while pending and pending[0][0] <= now:
-                d, k = heapq.heappop(pending)
-                if sess[k].flush_deadline == d:  # else stale: batch moved on
-                    sess[k].poll(now)
-                    _note(k)
+        e, last, steps = 0, -1, 0
+        while True:
+            r = events[e][0] if e < len(events) else n_runs
             if self.gang:
-                _poll_gang_edges(now)
-            i = j
+                r = min(r, _next_edge_run(last))
+            if r >= n_runs:
+                break
+            steps += 1
+            if self.gang and r > last + 1:
+                # the polls of the runs skipped saw no flush and no edge
+                # crossed: they only dropped the edges of empty queues
+                for sig, q in self._queues.items():
+                    if not q:
+                        next_edges.pop(sig, None)
+            while e < len(events) and events[e][0] == r:
+                _, _, _, k, start, end, stamp = events[e]
+                sess[k]._buffer(feeds[k][0][start:end], feeds[k][1][start:end])
+                sess[k].flush(now=stamp)
+                e += 1
+            if self.gang:
+                _poll_gang_edges(float(run_end[r]))
+            last = r
+        for k, start, end in residual:
+            sess[k]._buffer(feeds[k][0][start:end], feeds[k][1][start:end])
         # drain: every residual batch's timer fires after its oldest arrival
         for s in sess:
             if s.buffered:
                 s.flush(s.flush_deadline)
         if self.gang:
             self._dispatch_all(final=True)
-        spans.count("replay.tuples", n)
-        spans.count("replay.runs", runs)
+        spans.count("replay.tuples", sum(len(ts) for ts in tss))
+        spans.count("replay.runs", n_runs)
+        spans.count("replay.steps", steps)
 
     # ------------------------------------------------------------- report
     @spans.spanned("server.report")
