@@ -1277,39 +1277,47 @@ class ServerCore:
         mesh = None
         members = wave
         pad = 0
-        if self.fleet is not None and self.fleet.n_devices > 1:
-            mesh = self.fleet.mesh
-            pad = (-len(wave)) % self.fleet.n_devices
-            members = wave + [wave[0]] * pad
-        states = pipe.stack_states([s.state for s, _ in members])
-        blocks = np.stack([req.values.reshape(lanes, -1) for _, req in members])
-        masks = np.stack([req.mask.reshape(lanes, -1) for _, req in members])
-        if mesh is None:
-            blocks, masks = jnp.asarray(blocks), jnp.asarray(masks)
-        else:  # each device receives its own slice of the wave directly
-            sharding = NamedSharding(mesh, PartitionSpec("data"))
-            blocks, masks = jax.device_put((blocks, masks), sharding)
+        with spans.span("wave.assemble"):
+            if self.fleet is not None and self.fleet.n_devices > 1:
+                mesh = self.fleet.mesh
+                pad = (-len(wave)) % self.fleet.n_devices
+                members = wave + [wave[0]] * pad
+            states = pipe.stack_states([s.state for s, _ in members])
+            blocks = np.stack([req.values.reshape(lanes, -1) for _, req in members])
+            masks = np.stack([req.mask.reshape(lanes, -1) for _, req in members])
+            if mesh is None:
+                blocks, masks = jnp.asarray(blocks), jnp.asarray(masks)
+            else:  # each device receives its own slice of the wave directly
+                sharding = NamedSharding(mesh, PartitionSpec("data"))
+                blocks, masks = jax.device_put((blocks, masks), sharding)
         states, words, tbs, metas, wall = pipe.gang_step(
             states, blocks, masks, meta7=meta7, mesh=mesh
         )
-        tb_np = np.asarray(tbs)
+        with spans.span("wave.egress"):
+            tb_np = np.asarray(tbs)
+            segments, metas_np = self._fetch_wave_egress(pipe, wave, words, tb_np, metas)
         cost = wall / len(wave)  # the dispatch is shared; so is its cost
-        segments, metas_np = self._fetch_wave_egress(pipe, wave, words, tb_np, metas)
-        for i, (s, req) in enumerate(wave):  # pad slots sit past len(wave)
-            s.commit(
-                req,
-                pipe.unstack_state(states, i),
-                segments[i] if segments[i] is not None else words[i],
-                int(tb_np[i]),
-                metas_np[i] if metas_np is not None else metas[i],
-                cost,
-                meta_packed=meta7,
-            )
+        with spans.span("wave.commit"):
+            for i, (s, req) in enumerate(wave):  # pad slots sit past len(wave)
+                s.commit(
+                    req,
+                    pipe.unstack_state(states, i),
+                    segments[i] if segments[i] is not None else words[i],
+                    int(tb_np[i]),
+                    metas_np[i] if metas_np is not None else metas[i],
+                    cost,
+                    meta_packed=meta7,
+                )
         # modeled per-device time: the measured wall covers ALL padded
         # slots' work serialized; one device carried slots/mesh-width of it
         total_slots = len(members)
         shard_slots = total_slots // mesh.size if mesh is not None else total_slots
         self._device_busy_s += wall * (shard_slots / total_slots)
+        # once per completed wave, never per member: a wave that a device
+        # loss cut short is counted by its replay
+        spans.count("wave.slots", total_slots)
+        spans.count("wave.pad_slots", pad)
+        spans.count("wave.sharded", int(mesh is not None))
         if stats is not None:
             stats.n_waves += 1
             stats.sessions_dispatched += len(wave)
