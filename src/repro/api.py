@@ -174,7 +174,9 @@ class JobSpec:
     flush_tuples: int = 0  # 0 = one planned micro-batch block
     flush_timeout_s: float = 0.25
     # ---- egress / fidelity budget -------------------------------------------
-    #: keep wire frames and check the decode-fidelity contract
+    #: keep wire frames and check the decode-fidelity contract (server
+    #: sessions: when the report's `fidelity` is read, at `StreamHandle.
+    #: report()` and `close()`)
     egress: bool = False
     #: hard max-abs reconstruction budget; negotiation rejects codecs that
     #: cannot guarantee it (None = no budget)

@@ -218,8 +218,33 @@ class FlushRequest:
     timeout: bool
 
 
+class _EgressField:
+    """A `SessionReport` egress field (`fidelity`, `wire_bytes`,
+    `decode_s`). Its default is None; a report taken of an egress session
+    holds the session's `EgressSnapshot` instead, and the first read of any
+    of the three runs the snapshot's check and stores all three."""
+
+    def __set_name__(self, owner, name: str) -> None:
+        self.name = name
+
+    def __get__(self, rep, owner=None):
+        if rep is None:
+            return None  # the dataclass field's default
+        egress = rep.__dict__.pop("_egress", None)
+        if egress is not None:
+            rep.fidelity, rep.wire_bytes, rep.decode_s = egress.check()
+        return rep.__dict__[self.name]
+
+    def __set__(self, rep, value) -> None:
+        rep.__dict__[self.name] = value
+
+
 @dataclasses.dataclass
 class SessionReport:
+    """One session's accounting. The egress fields are checked when first
+    read, against the session as it stood when the report was taken: later
+    flushes, seals and dictionary swaps do not change them."""
+
     topic: str
     codec: str
     n_tuples: int
@@ -234,14 +259,20 @@ class SessionReport:
     p95_latency_s: float
     energy_j: float  # session's share of the scheduled profile energy
     # egress accounting (sessions created with egress=True only)
-    fidelity: Optional[metrics.Fidelity] = None  # decoded-vs-fed contract check
-    wire_bytes: Optional[int] = None  # serialized egress frame size
-    decode_s: Optional[float] = None  # egress decode wall time
+    fidelity: Optional[metrics.Fidelity] = _EgressField()  # decoded-vs-fed contract check
+    wire_bytes: Optional[int] = _EgressField()  # serialized egress frame size
+    decode_s: Optional[float] = _EgressField()  # egress decode wall time
     # adaptive sessions (DESIGN.md §16) only
     tier_switches: int = 0  # tier changes applied at flush boundaries
     tier_history: Tuple[str, ...] = ()  # tier that compressed each flush
     # trained-dictionary sessions (DESIGN.md §17) only
     dict_swaps: int = 0  # dictionary versions hot-swapped at flush boundaries
+    #: the session's egress state at report time; the egress fields read it
+    egress: dataclasses.InitVar[Optional["EgressSnapshot"]] = None
+
+    def __post_init__(self, egress: Optional["EgressSnapshot"]) -> None:
+        if egress is not None:
+            self._egress = egress
 
 
 @dataclasses.dataclass
@@ -311,6 +342,173 @@ class ServerReport:
     #: per-signature circuit-breaker snapshots keyed by `SignatureStats.
     #: label` (breaker-enabled servers only; DESIGN.md §18)
     breakers: Dict[str, dict] = dataclasses.field(default_factory=dict)
+
+
+class EgressSnapshot:
+    """One egress session's wire state at one moment, and what is built
+    from it on first use: the open segment's frame and the fidelity check
+    over every segment.
+
+    The inputs are fixed when taken: the sealed segments and the open
+    segment's blocks and fed values as tuples, and the codec state,
+    pipeline, config and tier then active (a switch or swap installs new
+    ones and never mutates these). So a report that holds a snapshot checks
+    the session as it stood, whatever flushes come later. The session keeps
+    the newest snapshot while its key holds (`StreamSession.
+    _egress_snapshot`), so the delivered frame, a seal and the check share
+    one marshal."""
+
+    def __init__(self, sess: "StreamSession", key: Tuple[int, int]):
+        self.key = key
+        self.session = sess  # its decompressor caches and fixed geometry
+        self.sealed = tuple(sess._sealed)
+        self.blocks = tuple(sess._egress_blocks)
+        self.values = tuple(sess._egress_values)
+        self.state = sess.state
+        self.pipeline = sess.pipeline
+        self.config = sess.config
+        self.tier = sess.active_tier
+        self._frame: Optional[bits.Frame] = None
+        self._check: Optional[tuple] = None
+
+    def frame(self) -> bits.Frame:
+        """The open segment's wire-format frame, marshalled on first use.
+
+        All flushed micro-batches become full blocks of the session's
+        capacity shape with per-block valid counts (partial/timeout flushes
+        were padded); `Codec.flush`'s trailing symbols (RLE's open run) are
+        packed as the flush mini-block. Each later use counts one
+        `egress.frame_reuses`."""
+        if self._frame is not None:
+            spans.count("egress.frame_reuses")
+            return self._frame
+        with spans.span("egress.frame"):
+            self._frame = self._marshal()
+        return self._frame
+
+    def _marshal(self) -> bits.Frame:
+        sess, pipe = self.session, self.pipeline
+        flush_entry = pipe.flush_block_entry(self.state)
+        flush_slots = 0 if flush_entry is None else pipe.flush_slots
+        n_full = len(self.blocks)
+        n_valid = sum(b[3] for b in self.blocks)
+        per_lane = sess.capacity // sess.lanes
+        if not sess._compact:
+            blocks = list(self.blocks)
+            if flush_entry is not None:
+                blocks.append(flush_entry)
+            return pipe.marshal_frame(
+                blocks,
+                per_lane=per_lane,
+                n_full=n_full,
+                tail_per_lane=0,
+                flush_slots=flush_slots,
+                n_valid=n_valid,
+            )
+        # compacted fast path: stored blocks are already wire-shaped —
+        # concatenate segments + splice the flush mini-block, header math only
+        segments = [b[0] for b in self.blocks]
+        block_bits = [b[1] for b in self.blocks]
+        block_valid = [b[3] for b in self.blocks]
+        flush_raw = np.zeros(0, np.int32)
+        if flush_entry is not None:
+            fw, fb, fbl, _ = flush_entry
+            segments.append(np.asarray(fw[: (int(fb) + 31) // 32], np.uint32))
+            block_bits.append(int(fb))
+            block_valid.append(0)
+            flush_raw = np.asarray(fbl, np.int32).ravel()
+        payload = (
+            np.concatenate(segments) if segments else np.zeros(0, np.uint32)
+        )
+        bitlen = packed_meta = None
+        if sess._meta_packed:
+            # session blocks splice word-aligned; the flush mini-block's raw
+            # bitlens host-pack onto the end (prefix symbols % 32 == 0)
+            packed_meta = np.concatenate(
+                [b[2] for b in self.blocks]
+                + [bits._pack_bitlens(flush_raw)]
+            ) if self.blocks or flush_raw.size else np.zeros(0, np.uint32)
+        else:
+            bitlen = np.concatenate(
+                [b[2] for b in self.blocks] + [flush_raw]
+            ) if self.blocks or flush_raw.size else np.zeros(0, np.int32)
+        return pipe.marshal_compacted(
+            per_lane=per_lane,
+            n_full=n_full,
+            tail_per_lane=0,
+            flush_slots=flush_slots,
+            n_valid=n_valid,
+            block_bits=np.asarray(block_bits, np.int64),
+            block_valid=np.asarray(block_valid, np.int64),
+            payload=payload,
+            bitlen=bitlen,
+            packed_meta=packed_meta,
+        )
+
+    def check(self) -> tuple:
+        """Decode every segment and check the fidelity contract, once.
+
+        Returns (Fidelity, wire_bytes, decode_wall_s): bit-exact for
+        lossless codecs, within `Codec.error_bound` for bounded lossy ones,
+        measured max-abs/RMSE/NRMSE regardless. Adaptive sessions decode
+        EVERY sealed tier segment with that tier's decompressor plus the
+        open segment, and check the contract over the concatenation — a
+        tier switch that corrupted either side of its boundary fails here."""
+        if self._check is None:
+            with spans.span("report.fidelity"):
+                self._check = self._decode_and_compare()
+        return self._check
+
+    def _decode_and_compare(self) -> tuple:
+        sess = self.session
+        decoded: List[np.ndarray] = []
+        feds: List[np.ndarray] = []
+        wire = 0
+        wall = 0.0
+        for frame, fed, tier in self.sealed:
+            if tier is not None and tier in sess._tiers:
+                decomp = sess._tier_decomp.get(tier)
+                if decomp is None:
+                    tier_cfg, tier_codec, _ = sess._tiers[tier]
+                    decomp = DecompressionPipeline(tier_cfg, codec=tier_codec)
+                    sess._tier_decomp[tier] = decomp
+            else:
+                # dictionary-swap seal (static session): decode with a codec
+                # carrying the frame's declared seed, so the check never
+                # depends on the process registry
+                decomp = sess._dict_decomp.get(frame.dict_id)
+                if decomp is None:
+                    codec = sess._dict_codecs.get(frame.dict_id, self.pipeline.codec)
+                    decomp = DecompressionPipeline(self.config, codec=codec)
+                    sess._dict_decomp[frame.dict_id] = decomp
+            dec = decomp.decompress(frame)
+            decoded.append(dec.values)
+            feds.append(fed)
+            wire += frame.wire_bytes
+            wall += dec.wall_s
+        if self.blocks:
+            frame = self.frame()
+            if sess.controller is not None:
+                # adaptive: the open segment's codec tracks the active tier
+                decomp = sess._tier_decomp.get(self.tier or "")
+                if decomp is None:
+                    decomp = DecompressionPipeline(
+                        self.config, codec=self.pipeline.codec
+                    )
+                    sess._tier_decomp[self.tier or ""] = decomp
+            else:
+                decomp = self.pipeline.decompressor()
+            dec = decomp.decompress(frame)
+            decoded.append(dec.values)
+            feds.append(np.concatenate(self.values))
+            wire += frame.wire_bytes
+            wall += dec.wall_s
+        fed_all = np.concatenate(feds) if feds else np.zeros(0, np.uint32)
+        dec_all = np.concatenate(decoded) if decoded else np.zeros(0, np.uint32)
+        fid = metrics.fidelity(
+            fed_all, dec_all, bound=self.pipeline.codec.error_bound()
+        )
+        return fid, wire, wall
 
 
 class StreamSession:
@@ -397,7 +595,9 @@ class StreamSession:
         #: legacy: (worst-case words, nbits, raw bitlens, valid)
         self._egress_blocks: List[tuple] = []
         self._egress_values: List[np.ndarray] = []
-        self._egress_cache: Optional[tuple] = None  # (n_blocks, fidelity triple)
+        #: the egress inputs as of the last ask, with the frame and the
+        #: check built from them (`_egress_snapshot`)
+        self._egress_now: Optional[EgressSnapshot] = None
         # ---- adaptive tier state (controller + tiers; DESIGN.md §16) ------
         #: the controller observing flush outcomes and picking rungs; None
         #: for ordinary (static) sessions
@@ -472,19 +672,15 @@ class StreamSession:
     def _seal_segment(self) -> None:
         """Close the active tier's accumulated blocks into one
         self-describing frame (fresh codec state follows, so stateful
-        decode replays each segment independently)."""
-        if not self.egress or not self._egress_blocks:
-            return
-        frame = self.egress_frame()
-        fed = (
-            np.concatenate(self._egress_values)
-            if self._egress_values
-            else np.zeros(0, np.uint32)
-        )
-        self._sealed.append((frame, fed, self.active_tier))
-        self._egress_blocks = []
-        self._egress_values = []
-        self._egress_cache = None
+        decode replays each segment independently). The pipeline and codec
+        state change next, so the egress snapshot goes either way."""
+        if self.egress and self._egress_blocks:
+            frame = self.egress_frame()
+            fed = np.concatenate(self._egress_values)
+            self._sealed.append((frame, fed, self.active_tier))
+            self._egress_blocks = []
+            self._egress_values = []
+        self._egress_now = None
 
     def _switch_tier(self, name: str) -> None:
         """Swap the session onto another rung AT a flush boundary: seal the
@@ -801,14 +997,21 @@ class StreamSession:
         return rec
 
     # ------------------------------------------------------------- egress
-    @spans.spanned("egress.frame")
-    def egress_frame(self) -> bits.Frame:
-        """Close the session's bitstream into one wire-format frame.
+    def _egress_snapshot(self) -> "EgressSnapshot":
+        """The session's egress inputs as they stand, taken once per
+        (sealed segments, open blocks): every flush appends a block and
+        every tier switch or dictionary swap seals, so the key moves with
+        any change the frame could see."""
+        key = (len(self._sealed), len(self._egress_blocks))
+        snap = self._egress_now
+        if snap is None or snap.key != key:
+            snap = self._egress_now = EgressSnapshot(self, key)
+        return snap
 
-        All flushed micro-batches become full blocks of the session's
-        capacity shape with per-block valid counts (partial/timeout flushes
-        were padded); `Codec.flush`'s trailing symbols (RLE's open run) are
-        packed as the flush mini-block. Leaves the session state untouched.
+    def egress_frame(self) -> bits.Frame:
+        """Close the session's bitstream into one wire-format frame
+        (`EgressSnapshot.frame`), built once per session state and shared
+        by `egress_frames()`, the segment seal and the fidelity check.
 
         The frame covers the session FROM ITS START: stateful decode must
         replay from the initial codec state, so egress blocks accumulate
@@ -817,134 +1020,14 @@ class StreamSession:
         letting one frame grow without bound."""
         if not self.egress:
             raise RuntimeError("session was not created with egress=True")
-        flush_entry = self.pipeline.flush_block_entry(self.state)
-        flush_slots = 0 if flush_entry is None else self.pipeline.flush_slots
-        n_full = len(self._egress_blocks)
-        n_valid = sum(b[3] for b in self._egress_blocks)
-        per_lane = self.capacity // self.lanes
-        if not self._compact:
-            blocks = list(self._egress_blocks)
-            if flush_entry is not None:
-                blocks.append(flush_entry)
-            return self.pipeline.marshal_frame(
-                blocks,
-                per_lane=per_lane,
-                n_full=n_full,
-                tail_per_lane=0,
-                flush_slots=flush_slots,
-                n_valid=n_valid,
-            )
-        # compacted fast path: stored blocks are already wire-shaped —
-        # concatenate segments + splice the flush mini-block, header math only
-        segments = [b[0] for b in self._egress_blocks]
-        block_bits = [b[1] for b in self._egress_blocks]
-        block_valid = [b[3] for b in self._egress_blocks]
-        flush_raw = np.zeros(0, np.int32)
-        if flush_entry is not None:
-            fw, fb, fbl, _ = flush_entry
-            segments.append(np.asarray(fw[: (int(fb) + 31) // 32], np.uint32))
-            block_bits.append(int(fb))
-            block_valid.append(0)
-            flush_raw = np.asarray(fbl, np.int32).ravel()
-        payload = (
-            np.concatenate(segments) if segments else np.zeros(0, np.uint32)
-        )
-        bitlen = packed_meta = None
-        if self._meta_packed:
-            # session blocks splice word-aligned; the flush mini-block's raw
-            # bitlens host-pack onto the end (prefix symbols % 32 == 0)
-            packed_meta = np.concatenate(
-                [b[2] for b in self._egress_blocks]
-                + [bits._pack_bitlens(flush_raw)]
-            ) if self._egress_blocks or flush_raw.size else np.zeros(0, np.uint32)
-        else:
-            bitlen = np.concatenate(
-                [b[2] for b in self._egress_blocks] + [flush_raw]
-            ) if self._egress_blocks or flush_raw.size else np.zeros(0, np.int32)
-        return self.pipeline.marshal_compacted(
-            per_lane=per_lane,
-            n_full=n_full,
-            tail_per_lane=0,
-            flush_slots=flush_slots,
-            n_valid=n_valid,
-            block_bits=np.asarray(block_bits, np.int64),
-            block_valid=np.asarray(block_valid, np.int64),
-            payload=payload,
-            bitlen=bitlen,
-            packed_meta=packed_meta,
-        )
+        return self._egress_snapshot().frame()
 
-    @spans.spanned("report.fidelity")
     def egress_fidelity(self):
-        """Decode the session's frame and check the fidelity contract.
-
-        Returns (Fidelity, wire_bytes, decode_wall_s): bit-exact for
-        lossless codecs, within `Codec.error_bound` for bounded lossy ones,
-        measured max-abs/RMSE/NRMSE regardless. Memoized on the segment +
-        flush counts, so repeated `report()` calls between flushes do not
-        re-frame and re-decode the whole session history.
-
-        Adaptive sessions decode EVERY sealed tier segment with that tier's
-        decompressor plus the open segment, and check the contract over the
-        concatenation — a tier switch that corrupted either side of its
-        boundary fails here."""
-        cache_key = (len(self._sealed), len(self._egress_blocks))
-        if self._egress_cache is not None and self._egress_cache[0] == cache_key:
-            return self._egress_cache[1]
-        decoded: List[np.ndarray] = []
-        feds: List[np.ndarray] = []
-        wire = 0
-        wall = 0.0
-        for frame, fed, tier in self._sealed:
-            if tier is not None and tier in self._tiers:
-                decomp = self._tier_decomp.get(tier)
-                if decomp is None:
-                    tier_cfg, tier_codec, _ = self._tiers[tier]
-                    decomp = DecompressionPipeline(tier_cfg, codec=tier_codec)
-                    self._tier_decomp[tier] = decomp
-            else:
-                # dictionary-swap seal (static session): decode with a codec
-                # carrying the frame's declared seed, so the check never
-                # depends on the process registry
-                decomp = self._dict_decomp.get(frame.dict_id)
-                if decomp is None:
-                    codec = self._dict_codecs.get(frame.dict_id, self.pipeline.codec)
-                    decomp = DecompressionPipeline(self.config, codec=codec)
-                    self._dict_decomp[frame.dict_id] = decomp
-            dec = decomp.decompress(frame)
-            decoded.append(dec.values)
-            feds.append(fed)
-            wire += frame.wire_bytes
-            wall += dec.wall_s
-        if self._egress_blocks:
-            frame = self.egress_frame()
-            if self.controller is not None:
-                # adaptive: the open segment's codec tracks the active tier
-                decomp = self._tier_decomp.get(self.active_tier or "")
-                if decomp is None:
-                    decomp = DecompressionPipeline(
-                        self.config, codec=self.pipeline.codec
-                    )
-                    self._tier_decomp[self.active_tier or ""] = decomp
-            else:
-                decomp = self.pipeline.decompressor()
-            dec = decomp.decompress(frame)
-            decoded.append(dec.values)
-            feds.append(
-                np.concatenate(self._egress_values)
-                if self._egress_values
-                else np.zeros(0, np.uint32)
-            )
-            wire += frame.wire_bytes
-            wall += dec.wall_s
-        fed_all = np.concatenate(feds) if feds else np.zeros(0, np.uint32)
-        dec_all = np.concatenate(decoded) if decoded else np.zeros(0, np.uint32)
-        fid = metrics.fidelity(
-            fed_all, dec_all, bound=self.pipeline.codec.error_bound()
-        )
-        out = (fid, wire, wall)
-        self._egress_cache = (cache_key, out)
-        return out
+        """Decode the session's frames and check the fidelity contract
+        (`EgressSnapshot.check`): (Fidelity, wire_bytes, decode_wall_s).
+        A report runs the same check when one of its egress fields is
+        first read."""
+        return self._egress_snapshot().check()
 
     # ------------------------------------------------------------- report
     def report(self, energy_j: float = 0.0) -> SessionReport:
@@ -957,9 +1040,6 @@ class StreamSession:
         lat_arr = np.array(lat, np.float64)
         mean_lat = float((lat_arr * weights).sum() / max(weights.sum(), 1.0))
         p95 = float(np.percentile(lat_arr, 95)) if len(lat_arr) else 0.0
-        fid = wire = dec_s = None
-        if self.egress and self.flushes:
-            fid, wire, dec_s = self.egress_fidelity()
         return SessionReport(
             topic=self.topic,
             codec=self.pipeline.codec.name,
@@ -974,12 +1054,10 @@ class StreamSession:
             mean_latency_s=mean_lat,
             p95_latency_s=p95,
             energy_j=energy_j,
-            fidelity=fid,
-            wire_bytes=wire,
-            decode_s=dec_s,
             tier_switches=self.tier_switches,
             tier_history=tuple(self.tier_history),
             dict_swaps=self.dict_swaps,
+            egress=self._egress_snapshot() if self.egress and self.flushes else None,
         )
 
 

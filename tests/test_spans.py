@@ -148,9 +148,11 @@ def test_count_and_snapshot_are_plain_data():
 #: the served path's spans, by the names the benchmark's readers use
 SERVED_SPANS = {
     "dispatcher.run", "server.replay", "server.wave", "wave.step",
-    "server.report", "report.fidelity", "egress.frame", "egress.rans",
+    "server.report", "egress.frame", "egress.rans",
     "frame.to_bytes", "collector.ingest", "collector.decode",
 }
+#: the span a report's egress field runs when it is first read
+REPORT_FIDELITY = "report.fidelity"
 
 
 def _serve(d, n=1500):
@@ -190,34 +192,45 @@ def _equal_topic_runs(feeds) -> int:
 
 
 def test_the_served_path_records_its_spans_and_counters():
-    """The counters pin today's served path: `report()` marshals and decodes
-    every session's frame once more besides the frame the handle delivers
-    and the collector decodes, so 2 marshals and 2 decodes per frame. A
-    change that takes the duplicate off the path updates these factors."""
+    """The counters pin the served path: `report()` neither marshals nor
+    decodes, so each delivered frame is marshalled once and decoded once,
+    at the collector. Reading the report's fidelity afterwards decodes each
+    session's frame once more and marshals none: it reuses the frame the
+    handle delivered."""
     d = cstream.Dispatcher(gang=True)
     before = spans.snapshot()
     feeds = _serve(d)
     got = spans.since(before)
     assert SERVED_SPANS <= set(got["spans"])
+    assert REPORT_FIDELITY not in got["spans"]
     c = got["counters"]
     assert c["replay.tuples"] == sum(len(v) for v, _ in feeds.values())
     assert c["replay.runs"] == _equal_topic_runs(feeds)
     assert c["handle.frames_delivered"] == len(feeds)
-    assert c["egress.frames_marshalled"] == 2 * c["handle.frames_delivered"]
+    assert c["egress.frames_marshalled"] == c["handle.frames_delivered"]
     assert c["collector.frames_ingested"] == len(feeds)
-    assert c["decode.frames"] == 2 * c["collector.frames_ingested"]
+    assert c["decode.frames"] == c["collector.frames_ingested"]
+    assert "egress.frame_reuses" not in c
     s = got["spans"]
-    # the replay's waves, and the report's decodes, are children
+    # the replay's waves are children
     assert s["dispatcher.run"]["total_s"] >= s["server.replay"]["total_s"] + s["server.report"]["total_s"]
     assert s["server.replay"]["self_s"] < s["server.replay"]["total_s"]
     assert s["server.wave"]["total_s"] >= s["wave.step"]["total_s"] > 0
-    assert s["server.report"]["total_s"] >= s["report.fidelity"]["total_s"]
     # one to_bytes and one ingest per delivered frame; rANS on four of six
     assert s["frame.to_bytes"]["calls"] == s["collector.ingest"]["calls"] == len(feeds)
-    assert s["egress.rans"]["calls"] == 2 * 4
+    assert s["egress.rans"]["calls"] == 4
+    assert s["egress.frame"]["calls"] == len(feeds)
     # every flush dispatch, gang or solo, is one timed wave.step
+    rep = d.report()
     assert s["wave.step"]["calls"] == sum(
-        st.n_waves + st.n_solo for st in d.report().dispatch_stats.values())
+        st.n_waves + st.n_solo for st in rep.dispatch_stats.values())
+
+    before = spans.snapshot()
+    assert all(rep.sessions[t].fidelity.bit_exact for t in feeds)
+    got = spans.since(before)
+    assert got["counters"] == {"egress.frame_reuses": len(feeds), "decode.frames": len(feeds)}
+    assert set(got["spans"]) == {REPORT_FIDELITY, "collector.decode"}
+    assert got["spans"][REPORT_FIDELITY]["calls"] == len(feeds)
 
 
 def test_the_spans_land_in_a_profiler_trace(tmp_path):
@@ -232,7 +245,9 @@ def test_the_spans_land_in_a_profiler_trace(tmp_path):
     opts.enable_hlo_proto = False
     jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
     try:
-        _serve(cstream.Dispatcher(gang=True), n=600)
+        traced = cstream.Dispatcher(gang=True)
+        _serve(traced, n=600)
+        assert all(r.fidelity.bit_exact for r in traced.report().sessions.values())
     finally:
         jax.profiler.stop_trace()
     paths = [os.path.join(r, f) for r, _, fs in os.walk(tmp_path) for f in fs
@@ -240,7 +255,7 @@ def test_the_spans_land_in_a_profiler_trace(tmp_path):
     pd = ProfileData.from_file(paths[0])
     names = {e.name for p in pd.planes for ln in p.lines for e in ln.events
              if e.name.startswith("cstream.")}
-    assert {"cstream." + n for n in SERVED_SPANS} <= names
+    assert {"cstream." + n for n in SERVED_SPANS | {REPORT_FIDELITY}} <= names
 
 
 def test_the_core_imports_the_recorder_without_the_server():
